@@ -25,7 +25,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -211,8 +210,8 @@ def make_lane_decode_step(cfg: ModelConfig, mesh: Mesh):
                     P(), P())
         out_specs = (P(), P(None, None, None, AXIS, None),
                      P(None, None, None, AXIS, None))
-        fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         logits, k_new, v_new = fn(params, cache["k"], cache["v"], token, pos)
         return logits, {"k": k_new, "v": v_new}
 

@@ -26,9 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, kvs_ref, o_ref,
@@ -114,7 +111,7 @@ def flash_decode(
             pltpu.VMEM((g, 128), jnp.float32),  # running denom
             pltpu.VMEM((g, d), jnp.float32),    # running output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,9 +26,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, kvs_ref, o_ref,
             m_ref, d_ref, acc_ref, *, page: int, n_p: int, scale: float):
@@ -119,10 +116,11 @@ def paged_flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_flash_decode",
     )(tables, lengths, q, k_pool, v_pool, kv_scale)
 
 

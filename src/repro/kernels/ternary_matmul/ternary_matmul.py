@@ -23,17 +23,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+
+def decode_slot(codes: jax.Array, s: int, dtype) -> jax.Array:
+    """Slot ``s`` of integer 2-bit codes → ternary {−1, 0, +1} in ``dtype``
+    ('01'→+1, '10'→−1, '00'→0: conditional negation, no multiplier)."""
+    c = (codes >> (2 * s)) & 3
+    return (c & 1).astype(dtype) - (c >> 1).astype(dtype)
 
 
 def _decode_tile(codes: jax.Array, layout: str, bk: int, bn: int, dtype) -> jax.Array:
     """uint8 (bk//4, bn) 2-bit codes → (bk, bn) ±1/0 in `dtype`."""
-    slots = []
-    for s in range(4):
-        c = (codes >> (2 * s)) & 3
-        # '01'→+1, '10'→−1, '00'→0: conditional negation, no multiplier.
-        slots.append(((c & 1).astype(jnp.int8) - ((c >> 1) & 1).astype(jnp.int8)))
+    slots = [decode_slot(codes, s, jnp.int8) for s in range(4)]
     if layout == "interleaved":
         w = jnp.stack(slots, axis=1).reshape(bk, bn)
     else:  # strided: slot s covers rows [s*bk/4, (s+1)*bk/4) of the tile
@@ -98,7 +98,7 @@ def ternary_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

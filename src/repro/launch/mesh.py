@@ -14,13 +14,8 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-# Hardware constants (TPU v5e; used by the roofline analysis)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (~per-direction)
-HBM_BYTES = 16 * 1024 ** 3      # 16 GiB per chip
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
@@ -28,12 +23,15 @@ MULTI_POD = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (elastic re-mesh / tests use small shapes)."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (elastic re-mesh / tests use small shapes). Every axis
+    is ``Auto``: the model code shards by ``with_sharding_constraint`` and
+    leaves propagation to the compiler, which jax's default explicit axes
+    reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: Optional[int] = None) -> Mesh:
@@ -42,7 +40,7 @@ def make_host_mesh(model: Optional[int] = None) -> Mesh:
     n = len(jax.devices())
     model = model or 1
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_chips(mesh: Mesh) -> int:

@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.ckpt import checkpoint as ckpt_mod
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import reduce_config
 from repro.models.transformer import Model
 from repro.serving import (DenseKV, PagedKV, ReplicaRouter, RequestSpec,
@@ -64,7 +65,9 @@ def build_engine(arch: str, preset: str, *, slots: int, max_len: int,
                  tracer=None, profiler=None) -> ServeEngine:
     cfg = reduce_config(get_config(arch), preset)
     model = Model(cfg, mode="serve")
-    params = model.init(jax.random.PRNGKey(seed))
+    # one compiled program: eager init would dispatch op by op and hold a
+    # full f32 weight stack per projection before packing it
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
     if ckpt_dir:
         step = ckpt_mod.latest_step(ckpt_dir)
         if step is not None:
@@ -223,6 +226,7 @@ def main(argv=None) -> int:
                          "%%-of-tick host overhead) as JSON to this path; "
                          "dispatches run blocked while profiling")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = None
     if args.trace_out:

@@ -32,6 +32,7 @@ from repro.ckpt import checkpoint as ckpt_mod
 from repro.configs.base import ModelConfig, ShapeConfig, get_config
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import specs as specs_mod
 from repro.launch import steps as steps_mod
 from repro.models import sharding as shard_rules
@@ -261,6 +262,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--data", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tc = TrainConfig(arch=args.arch, preset=args.preset, mode=args.mode,
                      steps=args.steps, batch=args.batch, seq=args.seq,

@@ -1,31 +1,31 @@
 """Hardware peak specs — the single source of truth for roofline math.
 
-Factored out of ``benchmarks/roofline.py`` (which previously hardcoded the
-TPU v5e peaks inline) so the offline roofline report, the live serving
+The offline roofline report (``benchmarks/roofline.py``), the live serving
 profiler (``repro.serving.obs.profile``) and the analytic memory model
-(``benchmarks/analytic_model``) all read the same numbers.
+(``benchmarks/analytic_model``) all read these numbers.
 
-Two specs ship:
+``PEAKS`` is keyed by ``jax.Device.device_kind``:
 
-* ``TPU_V5E`` — the paper's deployment target: 197 TFLOP/s bf16, 819 GB/s
-  HBM, ~50 GB/s per ICI link (conservative single-link figure), 16 GiB HBM.
-* ``CPU_HOST`` — an order-of-magnitude host fallback so the profiler
-  degrades gracefully when serving runs under ``JAX_PLATFORMS=cpu`` (CI,
-  dev boxes). Absolute efficiencies against it are directional only; the
-  memory-vs-compute *classification* is still meaningful because it depends
-  on operational intensity relative to the ridge point.
+* ``"TPU v5 lite"`` → ``TPU_V5E``, the paper's deployment target. Published
+  per-chip peaks (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+  819 GB/s HBM, 16 GiB HBM; ~50 GB/s per ICI link is a conservative
+  single-link figure.
+* ``"cpu"`` → ``CPU_HOST``, order-of-magnitude host numbers so the profiler
+  can classify memory- vs compute-bound under ``JAX_PLATFORMS=cpu``. Its
+  name says ``cpu-host``: efficiencies against it are not device numbers.
 
-``detect()`` picks by the active jax backend and never raises — off-TPU it
-always lands on ``CPU_HOST``.
+``detect()`` looks the first device's kind up and raises on a kind the
+table does not hold — an unknown accelerator never borrows another's peaks.
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Dict
 
 
 @dataclasses.dataclass(frozen=True)
 class HardwareSpec:
-    """Peak rates for one chip (or one host, for the CPU fallback)."""
+    """Peak rates for one chip (or one host, for the CPU entry)."""
 
     name: str
     peak_flops: float       # FLOP/s (bf16 on TPU)
@@ -56,7 +56,7 @@ class HardwareSpec:
         }
 
 
-#: TPU v5e, per chip. The numbers roofline.py shipped with since PR 0.
+#: TPU v5e, per chip.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops=197e12,
@@ -66,7 +66,7 @@ TPU_V5E = HardwareSpec(
 )
 
 #: Rough single-socket host: ~100 GFLOP/s sustained f32, ~20 GB/s DRAM.
-#: Deliberately conservative round numbers — a fallback, not a claim.
+#: Deliberately conservative round numbers, not a measurement.
 CPU_HOST = HardwareSpec(
     name="cpu-host",
     peak_flops=100e9,
@@ -75,12 +75,20 @@ CPU_HOST = HardwareSpec(
     hbm_bytes=8 * 1024 ** 3,
 )
 
+#: Peak table keyed by ``jax.Device.device_kind``.
+PEAKS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": CPU_HOST,
+}
+
 
 def detect() -> HardwareSpec:
-    """Spec for the active jax backend; CPU_HOST whenever not on TPU."""
+    """Spec for the first jax device's kind. Raises ``KeyError`` for a kind
+    with no entry in ``PEAKS``."""
+    import jax
+    device_kind = jax.devices()[0].device_kind
     try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    return TPU_V5E if backend == "tpu" else CPU_HOST
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak specs for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None

@@ -88,7 +88,6 @@ _SHARDMAP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import attention as CA
 
     mesh = jax.make_mesh((8,), ("model",))
@@ -101,7 +100,7 @@ _SHARDMAP_SCRIPT = textwrap.dedent("""
 
     for variant, fn in (("tom", CA.tom_flash_decode),
                         ("stock", CA.stock_flash_decode)):
-        sharded = shard_map(
+        sharded = jax.shard_map(
             partial(fn, axis_name="model"),
             mesh=mesh,
             in_specs=(P(), P(None, None, "model", None), P(None, None, "model", None)),

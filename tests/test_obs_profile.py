@@ -2,11 +2,13 @@
 cost/memory capture and structural-vs-XLA cross-check, SLO latency
 attribution golden cases, and the engine/gateway integration (profiled
 serving run → validated attribution report + attributed Prom counters)."""
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.obs.hardware import CPU_HOST, TPU_V5E, HardwareSpec, detect
+from repro.obs.hardware import CPU_HOST, PEAKS, TPU_V5E, HardwareSpec, detect
 from repro.serving.gateway.metrics import Metrics
 from repro.serving.obs import (ProfileRegistry, SLOAttribution, SLO_PHASES,
                                attribution_report, classify, validate_report)
@@ -26,9 +28,19 @@ class TestHardwareSpec:
         assert HW.roof_flops(1000.0) == pytest.approx(100e9)
 
     def test_detect_never_raises(self):
+        """On a device kind the table holds, detect() answers for it."""
         hw = detect()
-        assert hw in (CPU_HOST, TPU_V5E)
+        assert hw is PEAKS[jax.devices()[0].device_kind]
         assert hw.peak_flops > 0 and hw.hbm_bw > 0
+
+    def test_detect_keys_by_device_kind(self, monkeypatch):
+        assert PEAKS["TPU v5 lite"] is TPU_V5E
+        assert PEAKS["cpu"] is CPU_HOST
+        # an unknown accelerator never borrows the v5e peaks
+        monkeypatch.setattr(jax, "devices", lambda: [
+            types.SimpleNamespace(device_kind="TPU v4")])
+        with pytest.raises(KeyError, match="TPU v4"):
+            detect()
 
     def test_roofline_bench_shares_the_spec(self):
         from benchmarks import roofline
