@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the device, in
+the decode-heavy cell: the host holding the chip back (per-tick Python,
+sampling, admission). Moves ``output_tok_s``."""
+
+
+def read(ctx):
+    red = ctx["trace"]
+    if not red or not red["devices"] or red["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - red["busy_s"] / red["window_s"])
