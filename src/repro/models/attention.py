@@ -31,6 +31,7 @@ from repro.core import attention as core_attn
 from repro.core.lanes import tree_max, tree_sum
 from repro.models import act_sharding, layers
 from repro.models.layers import KV_CACHE_SCALE, Params, apply_linear, init_linear, linear_spec
+from repro.obs import names
 
 NEG_INF = -1e30
 
@@ -103,16 +104,18 @@ def gqa_decode_paged(p: Params, x: jax.Array, k_pool_l: jax.Array,
     positions = pos[:, None]
     q, k_new, v_new = _project_qkv(p, x[:, None], cfg, mode, positions, **kw)
     q = q[:, 0]                                          # (B, H, D)
-    k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_pool_l.dtype)
-    v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_pool_l.dtype)
-    # (B,) page ids / offsets, slice between them → batch dim leads: (B, H, D)
-    k_pool_l = k_pool_l.at[write_page, :, write_off].set(k_new)
-    v_pool_l = v_pool_l.at[write_page, :, write_off].set(v_new)
-    out = paged_decode_attention(
-        q, k_pool_l, v_pool_l, tables, lengths,
-        jnp.float32(KV_CACHE_SCALE), use_kernel=use_kernel,
-        interpret=interpret, out_dtype=jnp.float32)
-    out = out.reshape(b, cfg.q_dim).astype(x.dtype)
+    with names.scope(names.KV_APPEND):
+        k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_pool_l.dtype)
+        v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_pool_l.dtype)
+        # (B,) page ids / offsets, slice between them → batch dim leads
+        k_pool_l = k_pool_l.at[write_page, :, write_off].set(k_new)
+        v_pool_l = v_pool_l.at[write_page, :, write_off].set(v_new)
+    with names.scope(names.ATTN):
+        out = paged_decode_attention(
+            q, k_pool_l, v_pool_l, tables, lengths,
+            jnp.float32(KV_CACHE_SCALE), use_kernel=use_kernel,
+            interpret=interpret, out_dtype=jnp.float32)
+        out = out.reshape(b, cfg.q_dim).astype(x.dtype)
     return apply_linear(p["o"], out, mode, **kw), k_pool_l, v_pool_l
 
 

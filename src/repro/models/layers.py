@@ -15,6 +15,7 @@ and shard with PartitionSpec trees (models/sharding.py).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, Optional, Tuple
 
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fp8, qlora, ternary
+from repro.obs import names
 
 Params = Dict[str, jax.Array]
 
@@ -130,14 +132,16 @@ def apply_linear(p: Params, x: jax.Array, mode: str, *,
                  train: bool = False,
                  fuse: bool = False,
                  kv_dtype: str = "f32",
-                 adapter_idx: Optional[jax.Array] = None) -> jax.Array:  # noqa: ARG001
+                 adapter_idx: Optional[jax.Array] = None,
+                 scope: Optional[str] = names.TERNARY_PROJ) -> jax.Array:  # noqa: ARG001
     # ``fuse``/``kv_dtype`` are consumed by fused/attention call sites;
     # accepted (and ignored) here so the flags thread through **kw untouched.
     # ``adapter_idx`` (B,) selects each batch row's resident multi-tenant
     # adapter; it only acts on projections carrying a ``lora_mt`` stack.
     """The mode dispatch. In serve/qlora mode the base is ternary-packed ROM:
     decode-then-matmul (XLA fuses; the Pallas kernel path is selected by the
-    serving engine for the hot GEMVs where shapes allow)."""
+    serving engine for the hot GEMVs where shapes allow), named ``scope``
+    in the trace (None: the caller's own scope names it)."""
     if fp8_acts:
         x = fp8.fake_quantize(x)
     if mode == "qat":
@@ -145,16 +149,17 @@ def apply_linear(p: Params, x: jax.Array, mode: str, *,
         y = jnp.einsum("...k,kn->...n", x.astype(jnp.float32), w,
                        preferred_element_type=jnp.float32)
         return y.astype(x.dtype)
-    # §Perf: decode the 2-bit ROM to bf16, not f32 — ternary {−1,0,+1} is
-    # exact in bf16 and the dot still accumulates f32; halves the dominant
-    # dequant HBM traffic (the Pallas kernel decodes in-VMEM for free).
-    w = ternary.unpack2(p["packed"]).astype(jnp.bfloat16)
-    # ROM immutability: gradients must not reach the base weight/scale — but
-    # MUST keep flowing through x to earlier layers (stop-grad the weight
-    # side only, never the matmul output).
-    y = jnp.einsum("...k,kn->...n", x.astype(jnp.bfloat16), w,
-                   preferred_element_type=jnp.float32)
-    y = (y * jax.lax.stop_gradient(p["scale"])).astype(x.dtype)
+    with names.scope(scope) if scope else contextlib.nullcontext():
+        # §Perf: decode the 2-bit ROM to bf16, not f32 — ternary {−1,0,+1}
+        # is exact in bf16 and the dot still accumulates f32; halves the
+        # dominant dequant HBM traffic (the Pallas kernel decodes in-VMEM).
+        w = ternary.unpack2(p["packed"]).astype(jnp.bfloat16)
+        # ROM immutability: gradients must not reach the base weight/scale —
+        # but MUST keep flowing through x to earlier layers (stop-grad the
+        # weight side only, never the matmul output).
+        y = jnp.einsum("...k,kn->...n", x.astype(jnp.bfloat16), w,
+                       preferred_element_type=jnp.float32)
+        y = (y * jax.lax.stop_gradient(p["scale"])).astype(x.dtype)
     if mode == "qlora" and "lora" in p:
         y = y + qlora.adapter_path(x, p["lora"], lora or qlora.LoRASpec(),
                                    train=train).astype(y.dtype)
@@ -168,7 +173,9 @@ def _multi_tenant_lora(mt: Params, x: jax.Array, adapter_idx: jax.Array) -> jax.
     whose index is 0 hit the null adapter (zero codes, zero scale) and
     contribute exactly 0 — bit-identical to a no-adapter engine."""
     from repro.kernels.batched_lora import ops as blora_ops
-    return blora_ops.batched_lora(x, mt["a"], mt["b"], mt["s"], adapter_idx)
+    with names.scope(names.LORA):
+        return blora_ops.batched_lora(x, mt["a"], mt["b"], mt["s"],
+                                      adapter_idx)
 
 
 def apply_linear_fused(parts, x: jax.Array, mode: str, *,
@@ -196,15 +203,17 @@ def apply_linear_fused(parts, x: jax.Array, mode: str, *,
             outs.append(y[..., off:off + n].astype(x.dtype))
             off += n
         return outs
-    packed = jnp.concatenate([p["packed"] for p in parts], axis=-1)
-    w = ternary.unpack2(packed).astype(jnp.bfloat16)
-    y = jnp.einsum("...k,kn->...n", x.astype(jnp.bfloat16), w,
-                   preferred_element_type=jnp.float32)
+    with names.scope(names.TERNARY_PROJ):
+        packed = jnp.concatenate([p["packed"] for p in parts], axis=-1)
+        w = ternary.unpack2(packed).astype(jnp.bfloat16)
+        y = jnp.einsum("...k,kn->...n", x.astype(jnp.bfloat16), w,
+                       preferred_element_type=jnp.float32)
     outs, off = [], 0
     for p in parts:
         n = p["packed"].shape[-1]
-        yi = (y[..., off:off + n]
-              * jax.lax.stop_gradient(p["scale"])).astype(x.dtype)
+        with names.scope(names.TERNARY_PROJ):
+            yi = (y[..., off:off + n]
+                  * jax.lax.stop_gradient(p["scale"])).astype(x.dtype)
         if mode == "qlora" and "lora" in p:
             yi = yi + qlora.adapter_path(x, p["lora"], lora or qlora.LoRASpec(),
                                          train=train).astype(yi.dtype)
@@ -293,22 +302,27 @@ def embedding_spec(vocab: int, d: int, mode: str, dtype=jnp.bfloat16) -> Params:
 
 
 def embed_tokens(p: Params, tokens: jax.Array, mode: str, dtype=jnp.bfloat16) -> jax.Array:
-    if mode == "qat":
-        return p["w"][tokens].astype(dtype)
-    rows = p["packed_rows"][tokens]               # (..., D/4) uint8 gather
-    return (unpack_rows(rows).astype(jnp.float32) * p["scale"]).astype(dtype)
+    with names.scope(names.EMBED):
+        if mode == "qat":
+            return p["w"][tokens].astype(dtype)
+        rows = p["packed_rows"][tokens]           # (..., D/4) uint8 gather
+        return (unpack_rows(rows).astype(jnp.float32)
+                * p["scale"]).astype(dtype)
 
 
 def lm_head_logits(head_p: Params, x: jax.Array, mode: str) -> jax.Array:
     """x (..., D) → logits (..., V). Head weight layout is (D, V) (or the
     packed column form); tied embeddings pass the embedding params through
     models/transformer.py which transposes appropriately."""
-    return apply_linear(head_p, x, mode).astype(jnp.float32)
+    with names.scope(names.LM_HEAD):
+        return apply_linear(head_p, x, mode, scope=None).astype(jnp.float32)
 
 
 def tied_logits(embed_p: Params, x: jax.Array, mode: str) -> jax.Array:
-    if mode == "qat":
-        return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
-                          embed_p["w"].astype(jnp.float32))
-    w = unpack_rows(embed_p["packed_rows"]).astype(jnp.float32) * embed_p["scale"]
-    return jnp.einsum("...d,vd->...v", x.astype(jnp.float32), w)
+    with names.scope(names.LM_HEAD):
+        if mode == "qat":
+            return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
+                              embed_p["w"].astype(jnp.float32))
+        w = (unpack_rows(embed_p["packed_rows"]).astype(jnp.float32)
+             * embed_p["scale"])
+        return jnp.einsum("...d,vd->...v", x.astype(jnp.float32), w)

@@ -35,6 +35,7 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import layers, moe as moe_mod, ssm as ssm_mod
 from repro.models.layers import KV_CACHE_SCALE, Params
+from repro.obs import names
 
 NEG_INF = -1e30
 
@@ -151,26 +152,28 @@ def _gqa_decode_gspmd(p, x, k_cache, v_cache, pos, cfg, mode, **kw):
     positions = _pos2d(pos)
     q, k_new, v_new = attn_mod._project_qkv(p, x[:, None], cfg, mode, positions, **kw)
     q = q[:, 0].reshape(b, cfg.num_kv_heads, -1, cfg.head_dim)     # (B,Hkv,G,D)
-    k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_cache.dtype)
-    v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_cache.dtype)
-    k_cache = _update_cache_at(k_cache, k_new[:, :, None], pos, seq_axis=2)
-    v_cache = _update_cache_at(v_cache, v_new[:, :, None], pos, seq_axis=2)
+    with names.scope(names.KV_APPEND):
+        k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_cache.dtype)
+        v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_cache.dtype)
+        k_cache = _update_cache_at(k_cache, k_new[:, :, None], pos, seq_axis=2)
+        v_cache = _update_cache_at(v_cache, v_new[:, :, None], pos, seq_axis=2)
     s_len = k_cache.shape[2]
-    # §Perf C: widening the fp8 cache to bf16 instead of f32 halves the
-    # dominant decode HBM term; scores still accumulate in f32 via the dot's
-    # preferred_element_type.
-    wide = jnp.bfloat16 if kw.get("kv_dtype") == "bf16" else jnp.float32
-    kf = k_cache.astype(wide) * KV_CACHE_SCALE
-    vf = v_cache.astype(wide) * KV_CACHE_SCALE
-    scores = jnp.einsum("bhgd,bhsd->bhgs", q.astype(wide), kf,
-                        preferred_element_type=jnp.float32)
-    scores = scores * (cfg.head_dim ** -0.5)
-    if pos.ndim == 0:
-        mask = (jnp.arange(s_len) <= pos)[None, None, None, :]
-    else:
-        mask = (jnp.arange(s_len)[None] <= pos[:, None])[:, None, None, :]
-    out = _stable_softmax_attend(scores, vf, mask)
-    out = out.reshape(b, cfg.q_dim).astype(x.dtype)
+    with names.scope(names.ATTN):
+        # §Perf C: widening the fp8 cache to bf16 instead of f32 halves the
+        # dominant decode HBM term; scores still accumulate in f32 via the
+        # dot's preferred_element_type.
+        wide = jnp.bfloat16 if kw.get("kv_dtype") == "bf16" else jnp.float32
+        kf = k_cache.astype(wide) * KV_CACHE_SCALE
+        vf = v_cache.astype(wide) * KV_CACHE_SCALE
+        scores = jnp.einsum("bhgd,bhsd->bhgs", q.astype(wide), kf,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (cfg.head_dim ** -0.5)
+        if pos.ndim == 0:
+            mask = (jnp.arange(s_len) <= pos)[None, None, None, :]
+        else:
+            mask = (jnp.arange(s_len)[None] <= pos[:, None])[:, None, None, :]
+        out = _stable_softmax_attend(scores, vf, mask)
+        out = out.reshape(b, cfg.q_dim).astype(x.dtype)
     return layers.apply_linear(p["o"], out, mode, **kw), k_cache, v_cache
 
 
@@ -487,8 +490,9 @@ class Model:
                 h, (a2, b2), _ = attn_block_decode(lp, h, (a, b_), pos, cfg, mode, **kw)
                 return h, (a2, b2)
             x, (n0, n1) = jax.lax.scan(body, x, (p["layers"], c0[kd:], c1[kd:]))
-            c0 = jax.lax.dynamic_update_slice_in_dim(c0, n0, kd, 0)
-            c1 = jax.lax.dynamic_update_slice_in_dim(c1, n1, kd, 0)
+            with names.scope(names.KV_APPEND):
+                c0 = jax.lax.dynamic_update_slice_in_dim(c0, n0, kd, 0)
+                c1 = jax.lax.dynamic_update_slice_in_dim(c1, n1, kd, 0)
             new_cache = self._cache_unpair(cache, c0, c1)
 
         x = layers.rms_norm(x, p["final_norm"]["w"], cfg.norm_eps)
@@ -520,23 +524,26 @@ class Model:
         *inside* the jitted step, run the exact dense decode body on it, then
         scatter the new token's k/v back into its page. Op-for-op the dense
         math → token-identical dense↔paged greedy outputs."""
-        cache = {"k": attn_mod.gather_pages(state.k_pool, state.tables),
-                 "v": attn_mod.gather_pages(state.v_pool, state.tables)}
+        with names.scope(names.ATTN):
+            cache = {"k": attn_mod.gather_pages(state.k_pool, state.tables),
+                     "v": attn_mod.gather_pages(state.v_pool, state.tables)}
         logits, new_cache = self.decode_step(p, cache, token_or_embed, pos,
                                              adapter_idx)
-        # clip, don't fill: inactive slots carry a stale `pos` that can
-        # exceed the gathered view (their write lands on the scratch page
-        # and is never read), and jnp's OOB fill value is NaN — which would
-        # poison the scratch page and leak into live rows via table padding
-        idx = pos.reshape(1, -1, 1, 1, 1).astype(jnp.int32)
-        k_tok = jnp.take_along_axis(new_cache["k"], idx, axis=3,
-                                    mode="clip")[:, :, :, 0]
-        v_tok = jnp.take_along_axis(new_cache["v"], idx, axis=3,
-                                    mode="clip")[:, :, :, 0]
-        k_pool = attn_mod.scatter_tokens(state.k_pool, state.write_page,
-                                         state.write_off, k_tok)
-        v_pool = attn_mod.scatter_tokens(state.v_pool, state.write_page,
-                                         state.write_off, v_tok)
+        with names.scope(names.KV_APPEND):
+            # clip, don't fill: inactive slots carry a stale `pos` that can
+            # exceed the gathered view (their write lands on the scratch
+            # page and is never read), and jnp's OOB fill value is NaN —
+            # which would poison the scratch page and leak into live rows
+            # via table padding
+            idx = pos.reshape(1, -1, 1, 1, 1).astype(jnp.int32)
+            k_tok = jnp.take_along_axis(new_cache["k"], idx, axis=3,
+                                        mode="clip")[:, :, :, 0]
+            v_tok = jnp.take_along_axis(new_cache["v"], idx, axis=3,
+                                        mode="clip")[:, :, :, 0]
+            k_pool = attn_mod.scatter_tokens(state.k_pool, state.write_page,
+                                             state.write_off, k_tok)
+            v_pool = attn_mod.scatter_tokens(state.v_pool, state.write_page,
+                                             state.write_off, v_tok)
         return logits, dataclasses.replace(state, k_pool=k_pool, v_pool=v_pool)
 
     def _paged_decode_kernel(self, p, state, token_or_embed, pos, adapter_idx):
@@ -582,8 +589,9 @@ class Model:
             return h, (k2, v2)
 
         x, (n_k, n_v) = jax.lax.scan(body, x, (p["layers"], kp[kd:], vp[kd:]))
-        kp = jax.lax.dynamic_update_slice_in_dim(kp, n_k, kd, 0)
-        vp = jax.lax.dynamic_update_slice_in_dim(vp, n_v, kd, 0)
+        with names.scope(names.KV_APPEND):
+            kp = jax.lax.dynamic_update_slice_in_dim(kp, n_k, kd, 0)
+            vp = jax.lax.dynamic_update_slice_in_dim(vp, n_v, kd, 0)
         x = layers.rms_norm(x, p["final_norm"]["w"], cfg.norm_eps)
         return self._logits(p, x), dataclasses.replace(state, k_pool=kp,
                                                        v_pool=vp)
@@ -818,8 +826,9 @@ class Model:
             body = jax.checkpoint(body) if self.remat else body
             x, (n0, n1) = jax.lax.scan(
                 body, x, (p["layers"], c0[kd:], c1[kd:], pk[kd:], pv[kd:]))
-        c0 = jax.lax.dynamic_update_slice_in_dim(c0, n0, kd, 0)
-        c1 = jax.lax.dynamic_update_slice_in_dim(c1, n1, kd, 0)
+        with names.scope(names.KV_APPEND):
+            c0 = jax.lax.dynamic_update_slice_in_dim(c0, n0, kd, 0)
+            c1 = jax.lax.dynamic_update_slice_in_dim(c1, n1, kd, 0)
         cache = self._cache_unpair(cache, c0, c1)
         x = layers.rms_norm(x, p["final_norm"]["w"], cfg.norm_eps)
         return self._logits(p, x[:, -1]), cache
@@ -872,16 +881,20 @@ def _gqa_prefill_fill(p, h, k_cache, v_cache, cfg, mode, chunk, *,
     b, s, _ = h.shape
     positions = jnp.arange(s)[None, :] + pos_offset
     q, k, v = attn_mod._project_qkv(p, h, cfg, mode, positions, **kw)
-    if prefix_k is None:
-        out = attn_mod.chunked_causal_attention(q, k, v, chunk_q=min(chunk, s),
-                                                chunk_k=min(chunk, s))
-    else:
-        out = _attend_with_prefix(q, k, v, prefix_k, prefix_v, pos_offset)
+    with names.scope(names.ATTN):
+        if prefix_k is None:
+            out = attn_mod.chunked_causal_attention(
+                q, k, v, chunk_q=min(chunk, s), chunk_k=min(chunk, s))
+        else:
+            out = _attend_with_prefix(q, k, v, prefix_k, prefix_v, pos_offset)
     out = layers.apply_linear(p["o"], out.reshape(b, s, cfg.q_dim), mode, **kw)
-    k_c = (k / KV_CACHE_SCALE).transpose(0, 2, 1, 3).astype(k_cache.dtype)
-    v_c = (v / KV_CACHE_SCALE).transpose(0, 2, 1, 3).astype(v_cache.dtype)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k_c, (0, 0, pos_offset, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v_c, (0, 0, pos_offset, 0))
+    with names.scope(names.KV_APPEND):
+        k_c = (k / KV_CACHE_SCALE).transpose(0, 2, 1, 3).astype(k_cache.dtype)
+        v_c = (v / KV_CACHE_SCALE).transpose(0, 2, 1, 3).astype(v_cache.dtype)
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k_c,
+                                               (0, 0, pos_offset, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v_c,
+                                               (0, 0, pos_offset, 0))
     return out, k_cache, v_cache
 
 

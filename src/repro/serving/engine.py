@@ -91,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.transformer import Model
+from repro.obs.names import SERVE_SPANS
 from repro.serving.api import RequestSpec, SamplingParams, coerce_submit
 from repro.serving.kv import KVBackend, as_backend
 from repro.serving.obs.tracer import NULL_TRACER, CompileWatch, Tracer
@@ -246,12 +247,15 @@ class EngineStats:
     prefill_chunks: int = 0       # chunked-prefill segments run
     decode_stall_s: float = 0.0   # wall time decode slots waited on prefill
     spec_ticks: int = 0           # ticks that ran the multi-token verify
+    decode_steps: int = 0         # decode-executable dispatches (``ticks``
+                                  # also counts prefill-only and verify ticks)
     spec_drafted: int = 0         # draft tokens proposed across all requests
     spec_accepted: int = 0        # draft tokens accepted (extra tokens/tick)
     wall_s: float = 0.0
     # observability: per-phase self-time (ms) accumulated across ticks —
-    # schedule / prefill / prefill_chunk / decode / spec_verify / sample /
-    # commit / emit; nested phases subtract, so values sum to tick wall
+    # the phases of ``repro.obs.names.PHASES`` (schedule / admit / prefill /
+    # kv_write / decode / sample / commit / emit / wait_device, ...);
+    # nested phases subtract, so values sum to the timed wall
     phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
     tick_gap_ms_sum: float = 0.0  # host time between device dispatches
     tick_gaps: int = 0
@@ -307,17 +311,22 @@ class EngineStats:
 
 
 class _Phase:
-    """Phase timer + optional trace span. Accumulates *self-time* into
+    """Phase timer + trace spans. Accumulates *self-time* into
     ``stats.phase_ms`` — a nested phase's time is subtracted from its
     parent (via the engine's running self-time total), so the per-phase
-    breakdown sums to tick wall time instead of double-counting."""
-    __slots__ = ("eng", "name", "t0", "self0", "span")
+    breakdown sums to tick wall time instead of double-counting. Opens a
+    ``serve.<phase>`` span on the ``jax.profiler`` clock (nothing is
+    recorded unless a profiler trace is running) and, when the engine has
+    an enabled ``Tracer``, a span in its Chrome trace."""
+    __slots__ = ("eng", "name", "t0", "self0", "span", "ann")
 
     def __init__(self, eng: "ServeEngine", name: str):
         self.eng = eng
         self.name = name
 
     def __enter__(self):
+        self.ann = jax.profiler.TraceAnnotation(SERVE_SPANS[self.name])
+        self.ann.__enter__()
         self.span = self.eng.trace.span(self.name, pid=self.eng._tpid)
         self.span.__enter__()
         self.self0 = self.eng._phase_self_total
@@ -331,7 +340,9 @@ class _Phase:
         pm = self.eng.stats.phase_ms
         pm[self.name] = pm.get(self.name, 0.0) + own
         self.eng._phase_self_total = self.self0 + nested + own
-        return self.span.__exit__(*exc)
+        self.span.__exit__(*exc)
+        self.ann.__exit__(*exc)
+        return False
 
 
 @dataclasses.dataclass
@@ -566,7 +577,8 @@ class ServeEngine:
 
     # -- observability helpers -------------------------------------------------
     def _phase(self, name: str) -> _Phase:
-        """Tick-phase timer (+ trace span when the tracer is enabled)."""
+        """Tick-phase timer and ``serve.<name>`` span; ``name`` is one of
+        ``repro.obs.names.PHASES``."""
         return _Phase(self, name)
 
     def _note_compile(self, name: str, shapes: str) -> None:
@@ -612,8 +624,8 @@ class ServeEngine:
         return out
 
     #: phases counted as device-execution time for the energy monitor
-    _BUSY_PHASES = ("prefill", "prefill_chunk", "decode", "spec_verify",
-                    "sample", "commit")
+    _BUSY_PHASES = ("prefill", "prefill_chunk", "kv_write", "decode",
+                    "spec_verify", "sample", "commit")
 
     def _busy_ms(self) -> float:
         pm = self.stats.phase_ms
@@ -1393,7 +1405,8 @@ class ServeEngine:
                 _, sub_cache = self.model.prefill(
                     self._effective_params(),
                     {"tokens": jnp.asarray(toks)}, self.max_len, **kwargs)
-            self.kv.write_prefill(slot, start, sub_cache, n)
+            with self._phase("kv_write"):
+                self.kv.write_prefill(slot, start, sub_cache, n)
             self.pos[slot] = start + n
             stalled = [i for i in range(self.max_slots)
                        if i != slot and self._is_decoding(i)]
@@ -1734,7 +1747,7 @@ class ServeEngine:
                 if len(self.pending_prompt[i]) > 1:
                     # mid-prompt (token-mode prefill): commit the fed token's
                     # KV and keep consuming — drafting was ineligible here
-                    with self._phase("commit"):
+                    with self._phase("commit"), self._phase("kv_write"):
                         self.kv.commit_span(i, int(self.pos[i]), spans, 1)
                     self.pos[i] += 1
                     self._pop_pending(i)
@@ -1746,7 +1759,7 @@ class ServeEngine:
                                  eos_id=req.eos_id)
                 # commit before _pop_pending: trie donation of a page-aligned
                 # prompt needs the fed token's KV in its page already
-                with self._phase("commit"):
+                with self._phase("commit"), self._phase("kv_write"):
                     self.kv.commit_span(i, int(self.pos[i]), spans,
                                         len(emit))
                 self._pop_pending(i)
@@ -1821,7 +1834,8 @@ class ServeEngine:
         t0 = time.perf_counter()
         with self.trace.span("tick_finish", pid=self._tpid):
             if p.nxt_dev is not None:
-                nxt = np.asarray(p.nxt_dev)
+                with self._phase("wait_device"):
+                    nxt = np.asarray(p.nxt_dev)
                 now = time.time()
                 with self._phase("emit"):
                     for i, req, pos_i in p.emits:
@@ -1847,7 +1861,8 @@ class ServeEngine:
     def _tick_begin_impl(self, p: PendingTick) -> None:
         with self._phase("schedule"):
             self._prefetch_queue()
-            self._admit()
+            with self._phase("admit"):
+                self._admit()
         chunks = self._advance_prefill()
         active = [i for i in range(self.max_slots) if self._is_decoding(i)
                   and not self._slot_done_inflight(i)]
@@ -1913,6 +1928,7 @@ class ServeEngine:
                 self._decode, self._effective_params(), state,
                 fed, jnp.asarray(self.pos.copy()),
                 self._adapter_idx())
+            self.stats.decode_steps += 1
         with self._phase("commit"):
             self.kv.commit(new_state, active, self.pos)
         with self._phase("sample"):

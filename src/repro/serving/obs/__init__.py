@@ -4,8 +4,8 @@ exposition, live energy/power-gating gauges and performance attribution.
 Dependency-free (stdlib + the repo's own analytical models). Five pieces,
 each usable alone:
 
-  * `obs.tracer` — `Tracer`: nested per-tick phase spans (tick → schedule /
-    prefill_chunk / decode / spec_verify / sample / commit / emit),
+  * `obs.tracer` — `Tracer`: nested per-tick phase spans (tick → the
+    phases of `repro.obs.names.PHASES`),
     per-request lifecycle tracks (queued → prefilling → decoding → done,
     with preempt/cancel edges) and jit-recompile instants, exported as
     Chrome ``trace_event`` JSON(L) loadable in Perfetto. A ring-buffer mode

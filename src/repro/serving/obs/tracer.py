@@ -3,9 +3,10 @@
 One `Tracer` records three kinds of activity:
 
   * **phase spans** (`span`): nested complete ("X") events on a per-engine
-    "tick" track — the engine wraps each tick and its phases (schedule /
-    prefill_chunk / decode / spec_verify / sample / commit / emit) so a
-    captured trace shows exactly where a tick's time goes;
+    "tick" track — the engine wraps each tick and its phases (those of
+    ``repro.obs.names.PHASES``: schedule / admit / prefill / kv_write /
+    decode / sample / commit / emit / ...) so a captured trace shows
+    exactly where a tick's time goes;
   * **request lifecycle tracks** (`lifecycle`): each request uid gets its
     own track; every state (queued → prefilling → decoding) is one "X"
     span from state entry to exit, terminal states (done / cancelled /
@@ -244,6 +245,10 @@ class CompileWatch:
         self.last_compiled = False
         self._lock = threading.Lock()
         self._seen_sigs: set = set()
+        # (params pytree, its signature) of the last call: the engine hands
+        # every step the same params object until an adapter load rebuilds
+        # it, so flattening it again each call is wasted host time
+        self._params_sig: Optional[tuple] = None
 
     @staticmethod
     def _shapes(args) -> str:
@@ -287,8 +292,18 @@ class CompileWatch:
             parts.append(repr(sorted(kwargs.items())))
         return "|".join(parts)
 
+    def _call_sig(self, args, kwargs) -> str:
+        """``_sig`` of a call whose leading params pytree (a dict) keeps
+        its signature while it is the same object as on the last call."""
+        if not args or not isinstance(args[0], dict):
+            return self._sig(args, kwargs)
+        cached = self._params_sig
+        if cached is None or cached[0] is not args[0]:
+            cached = self._params_sig = (args[0], self._sig(args[:1], None))
+        return cached[1] + "|" + self._sig(args[1:], kwargs)
+
     def __call__(self, *args, **kwargs):
-        sig = self._sig(args, kwargs)
+        sig = self._call_sig(args, kwargs)
         with self._lock:
             compiled = sig not in self._seen_sigs
             self._seen_sigs.add(sig)

@@ -30,6 +30,11 @@ paper's distributed ROM-bank architecture exists to avoid, quantified by
                     API (submit / cancel / drain / quiesce / close). A
                     poisoned runtime never hangs a waiter.
 
+In a ``jax.profiler`` trace each thread names its own work
+(``repro.obs.names``): the dispatch thread ``serve.<phase>`` (the engine's
+tick phases plus ``serve.inbox`` / ``serve.idle``), the backlog thread
+``backlog.<event>``, a submitting client ``client.bind``.
+
 Token identity: the engine's split-tick pipeline feeds in-flight slots
 their unmaterialized token via a device-side overlay and offsets seeded
 sampling steps by the in-flight count, so seeded/greedy async output is
@@ -41,6 +46,10 @@ import queue
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
+
+from repro.obs.names import BACKLOG_SPANS, CLIENT_BIND
 
 TERMINAL_STATES = ("done", "cancelled", "expired", "rejected", "error")
 
@@ -267,7 +276,8 @@ class AsyncServeRuntime:
         self._inbox.put(("submit", (list(prompt), spec, sampling), ticket),
                         timeout=timeout)
         try:
-            ticket.wait_bound(timeout)
+            with jax.profiler.TraceAnnotation(CLIENT_BIND):
+                ticket.wait_bound(timeout)
         except TimeoutError:
             self._check_poison()
             raise
@@ -379,11 +389,13 @@ class AsyncServeRuntime:
         eng = self.eng
         try:
             while not self._stop.is_set():
-                self._drain_inbox()
+                with eng._phase("inbox"):
+                    self._drain_inbox()
                 if not (len(eng.scheduler)
                         or any(r is not None for r in eng.slot_req)):
                     eng._settle_pipeline()
-                    self._drain_inbox(timeout=0.02)
+                    with eng._phase("idle"):
+                        self._drain_inbox(timeout=0.02)
                     continue
                 ticks0 = eng.stats.ticks
                 t0 = time.perf_counter()
@@ -396,7 +408,8 @@ class AsyncServeRuntime:
                     # nothing can admit must not busy-spin the loop
                     eng._settle_pipeline()
                     if not any(r is not None for r in eng.slot_req):
-                        self._drain_inbox(timeout=0.02)
+                        with eng._phase("idle"):
+                            self._drain_inbox(timeout=0.02)
             # graceful stop: flush the pipeline so every sampled token is
             # emitted before the backlog drains
             eng._settle_pipeline()
@@ -449,7 +462,8 @@ class AsyncServeRuntime:
                 evt = self._events.get()
                 if evt is _STOP:
                     break
-                self._handle_event(evt)
+                with jax.profiler.TraceAnnotation(BACKLOG_SPANS[evt[0]]):
+                    self._handle_event(evt)
         except BaseException as exc:      # noqa: BLE001 — supervisor contract
             self._poison_with(exc)
             self._cleanup_tickets()
