@@ -110,9 +110,10 @@ def run(quick: bool = False) -> Report:
         g = hq // hkv
         n_pages = b * n_p + 1                      # +1 scratch page
         q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
-        k_pool = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)),
+        # one layer: the pool's leading layer axis has size 1
+        k_pool = jnp.asarray(rng.normal(size=(1, n_pages, hkv, page, d)),
                              jnp.float32)
-        v_pool = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)),
+        v_pool = jnp.asarray(rng.normal(size=(1, n_pages, hkv, page, d)),
                              jnp.float32)
         tables = jnp.asarray(
             rng.permutation(b * n_p).reshape(b, n_p) + 1, jnp.int32)
@@ -120,7 +121,8 @@ def run(quick: bool = False) -> Report:
             rng.integers(page, n_p * page + 1, size=b), jnp.int32)
         t_ref = time_fn(lambda: jax.block_until_ready(
             fd_ops.paged_decode_attention(q, k_pool, v_pool, tables, lengths,
-                                          use_kernel=False)), iters=3)
+                                          layer=0, use_kernel=False)),
+            iters=3)
         s_ctx = float(jnp.sum(lengths))            # live tokens attended
         flops = 4.0 * hq * d * s_ctx
         # traffic: q/out + the gathered pages (kernel DMAs exactly the

@@ -15,7 +15,8 @@ contexts on their second KV page, one decode step of the Pallas kernel
 path (``Model(paged_attn="kernel")``) is compared on logits against the
 XLA gather path (``paged_attn="gather"``), ``paged_flash_decode`` alone is
 compared with its XLA reference on every layer of the live pool, and the
-engine's compiled decode step is checked for both Pallas kernels.
+engine's compiled decode step is checked for both Pallas kernels and for
+aliasing both KV pools (it consumes the pool it is handed).
 
 ``--replicas N``: N full-width replicas, each on its own chip, serve the
 same requests behind ``ReplicaRouter`` as a one-replica engine on device 0;
@@ -102,8 +103,9 @@ def serve_and_probe(gw, work, min_pos: int = 0):
     """Submit ``work`` through ``gw`` and tick until every slot that can be
     busy is decoding and one of them feeds position ``min_pos`` or later;
     return the requests and the arguments of the next decode step (live
-    engine state, nothing committed), with the workload index of each live
-    slot. The caller drains the gateway."""
+    engine state, nothing committed, the pools copied: the engine's decode
+    step consumes the pools it is handed), with the workload index of each
+    live slot. The caller drains the gateway."""
     import jax.numpy as jnp
     eng = gw.engine
     reqs = [gw.submit(p, s, sp) for p, s, sp in work]
@@ -121,8 +123,11 @@ def serve_and_probe(gw, work, min_pos: int = 0):
     fed = np.zeros((eng.max_slots,), np.int32)
     for i in active:
         fed[i] = eng._fed_token(i)
-    args = (eng._effective_params(), eng.kv.decode_state(active, eng.pos),
-            jnp.asarray(fed), jnp.asarray(eng.pos.copy()), eng._adapter_idx())
+    state = eng.kv.decode_state(active, eng.pos)
+    state = dataclasses.replace(state, k_pool=jnp.copy(state.k_pool),
+                                v_pool=jnp.copy(state.v_pool))
+    args = (eng._effective_params(), state, jnp.asarray(fed),
+            jnp.asarray(eng.pos.copy()), eng._adapter_idx())
     index = {id(r): j for j, r in enumerate(reqs)}
     return reqs, args, active, [index[id(eng.slot_req[i])] for i in active]
 
@@ -175,8 +180,9 @@ def attention_check(state, active, num_heads: int, seed: int):
 
     def attend(layer, lengths, use_kernel):
         out = paged_decode_attention(
-            q, state.k_pool[layer], state.v_pool[layer], state.tables,
-            lengths, jnp.float32(KV_CACHE_SCALE), use_kernel=use_kernel)
+            q, state.k_pool, state.v_pool, state.tables, lengths,
+            jnp.float32(KV_CACHE_SCALE), layer=jnp.int32(layer),
+            use_kernel=use_kernel)
         return np.asarray(out, np.float32)[rows]
 
     first_page = jnp.minimum(state.lengths, PAGE)
@@ -203,6 +209,13 @@ def one_chip(devices, preset: str = "full", seed: int = 0) -> None:
     jax.block_until_ready(eng.params)
     _log(f"build + AOT warmup {time.perf_counter() - t0:.1f} s "
          f"({warm['compiles']} compiles; information only)")
+    pool_bytes = eng.pool.k.nbytes + eng.pool.v.nbytes
+    _log(f"compiled decode step, largest table view: aliases "
+         f"{warm['decode_alias_bytes']} B of arguments to outputs (KV pools "
+         f"{pool_bytes} B), temporaries {warm['decode_temp_bytes']} B")
+    if not warm["decode_alias_bytes"] >= pool_bytes:
+        raise AssertionError("the decode step does not update the KV pools "
+                             "in place")
 
     gw = Gateway(eng)
     work = workload(cfg.vocab_size, 12, (24, 30, 40, 62), seed)

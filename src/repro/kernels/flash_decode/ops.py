@@ -60,12 +60,13 @@ def decode_attention(
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret", "out_dtype"))
 def paged_decode_attention(
     q: jax.Array,          # (B, Hq, D)
-    k_pool: jax.Array,     # (n_pages, Hkv, page, D)  shared PagePool layer
+    k_pool: jax.Array,     # (L, n_pages, Hkv, page, D)  shared PagePool
     v_pool: jax.Array,
     tables: jax.Array,     # (B, n_p) int32 block tables (pad → scratch page)
     lengths: jax.Array,    # (B,) int32 live context length per sequence
     kv_scale: jax.Array = 1.0,
     *,
+    layer: jax.Array,      # int32 () layer of the pool to attend over
     use_kernel: bool = True,
     interpret: bool | None = None,
     out_dtype=jnp.float32,
@@ -74,9 +75,11 @@ def paged_decode_attention(
 
     The serving engine's block tables (`PagePool.batch_tables`) drive the
     kernel's page-shaped context loop via scalar prefetch — no contiguous
-    gather. fp8 pools are widened per-tile inside the kernel."""
+    gather. The kernel reads ``layer`` of the whole pool in place (a
+    one-layer pool is ``pool[None]`` with layer 0). fp8 pools are widened
+    per-tile inside the kernel."""
     b, hq, d = q.shape
-    _, hkv, _, _ = k_pool.shape
+    _, _, hkv, _, _ = k_pool.shape
     assert hq % hkv == 0, (hq, hkv)
     qg = q.reshape(b, hkv, hq // hkv, d)
 
@@ -91,8 +94,9 @@ def paged_decode_attention(
 
     if use_kernel:
         out = paged_flash_decode(qg, k_pool, v_pool, tables, lengths, kv_scale,
-                                 out_dtype=out_dtype, interpret=interpret)
+                                 layer, out_dtype=out_dtype,
+                                 interpret=interpret)
     else:
-        out = paged_flash_decode_ref(qg, k_pool, v_pool, tables, lengths,
-                                     kv_scale, out_dtype=out_dtype)
+        out = paged_flash_decode_ref(qg, k_pool[layer], v_pool[layer], tables,
+                                     lengths, kv_scale, out_dtype=out_dtype)
     return out.reshape(b, hq, d)

@@ -25,12 +25,14 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import attention as core_attn
 from repro.core.lanes import tree_max, tree_sum
 from repro.models import act_sharding, layers
 from repro.models.layers import KV_CACHE_SCALE, Params, apply_linear, init_linear, linear_spec
+from repro.models.sharding import paged_pool_spec
 from repro.obs import names
 
 NEG_INF = -1e30
@@ -85,38 +87,104 @@ def scatter_tokens(pool: jax.Array, page_ids: jax.Array, offsets: jax.Array,
         toks.astype(pool.dtype).transpose(1, 0, 2, 3))
 
 
-def gqa_decode_paged(p: Params, x: jax.Array, k_pool_l: jax.Array,
-                     v_pool_l: jax.Array, tables: jax.Array,
+def gqa_decode_paged(p: Params, x: jax.Array, k_pool: jax.Array,
+                     v_pool: jax.Array, layer: jax.Array, tables: jax.Array,
                      write_page: jax.Array, write_off: jax.Array,
                      lengths: jax.Array, pos: jax.Array, cfg: ModelConfig,
-                     mode: str, *, use_kernel: bool, interpret: bool,
+                     mode: str, *, interpret: bool,
                      **kw) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token GQA decode straight off one layer of the paged KV pool.
 
-    Scatters the new token's k/v into its page, then dispatches attention to
-    the Pallas ``paged_flash_decode`` kernel (block tables via scalar
-    prefetch, pages stream HBM→VMEM — no contiguous gather) or its XLA
-    gather reference. x: (B, D); k_pool_l/v_pool_l: (N+1, Hkv, page, D).
-    Returns (out (B, D), new k_pool_l, new v_pool_l).
+    Writes the new token's k/v into its page of ``layer`` with the Pallas
+    ``paged_kv_append`` kernel, then runs attention over that layer with the
+    Pallas ``paged_flash_decode`` kernel (block tables via scalar prefetch,
+    pages stream HBM→VMEM — no contiguous gather). x: (B, D);
+    k_pool/v_pool: (L, N+1, Hkv, page, D), the whole pool; layer: int32 ().
+    Both kernels take the whole pool, and the append aliases it to its
+    output: inside a layer scan that carries the pools, the write updates
+    the carried buffer in place and no layer of the pool is ever copied.
+    A pool that spans several devices runs the kernels per lane
+    (`_append_attend_on_lanes`).
+    Returns (out (B, D), new k_pool, new v_pool).
     """
-    from repro.kernels.flash_decode.ops import paged_decode_attention
     b, _ = x.shape
     positions = pos[:, None]
     q, k_new, v_new = _project_qkv(p, x[:, None], cfg, mode, positions, **kw)
     q = q[:, 0]                                          # (B, H, D)
     with names.scope(names.KV_APPEND):
-        k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_pool_l.dtype)
-        v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_pool_l.dtype)
-        # (B,) page ids / offsets, slice between them → batch dim leads
-        k_pool_l = k_pool_l.at[write_page, :, write_off].set(k_new)
-        v_pool_l = v_pool_l.at[write_page, :, write_off].set(v_new)
+        k_new = (k_new[:, 0] / KV_CACHE_SCALE).astype(k_pool.dtype)
+        v_new = (v_new[:, 0] / KV_CACHE_SCALE).astype(v_pool.dtype)
+    args = (q, k_new, v_new, k_pool, v_pool, layer, tables, write_page,
+            write_off, lengths)
+    mesh = jax.typeof(k_pool).sharding.mesh
+    if mesh.empty or mesh.size == 1:
+        out, k_pool, v_pool = _append_attend(*args, interpret=interpret)
+    else:
+        out, k_pool, v_pool = _append_attend_on_lanes(mesh, *args,
+                                                      interpret=interpret)
+    with names.scope(names.ATTN):
+        out = out.reshape(b, cfg.q_dim).astype(x.dtype)
+    return apply_linear(p["o"], out, mode, **kw), k_pool, v_pool
+
+
+def _append_attend(q, k_new, v_new, k_pool, v_pool, layer, tables,
+                   write_page, write_off, lengths, *, interpret: bool):
+    """The two Pallas kernels of `gqa_decode_paged` on one device's pool:
+    write each slot's row into ``layer``, then attend over that layer."""
+    from repro.kernels.flash_decode.ops import paged_decode_attention
+    from repro.kernels.flash_decode.paged import paged_kv_append
+    with names.scope(names.KV_APPEND):
+        k_pool, v_pool = paged_kv_append(k_pool, v_pool, k_new, v_new, layer,
+                                         write_page, write_off,
+                                         interpret=interpret)
     with names.scope(names.ATTN):
         out = paged_decode_attention(
-            q, k_pool_l, v_pool_l, tables, lengths,
-            jnp.float32(KV_CACHE_SCALE), use_kernel=use_kernel,
-            interpret=interpret, out_dtype=jnp.float32)
-        out = out.reshape(b, cfg.q_dim).astype(x.dtype)
-    return apply_linear(p["o"], out, mode, **kw), k_pool_l, v_pool_l
+            q, k_pool, v_pool, tables, lengths, jnp.float32(KV_CACHE_SCALE),
+            layer=layer, use_kernel=True, interpret=interpret,
+            out_dtype=jnp.float32)
+    return out, k_pool, v_pool
+
+
+def _append_attend_on_lanes(mesh, q, k_new, v_new, k_pool, v_pool, layer,
+                            tables, write_page, write_off, lengths, *,
+                            interpret: bool):
+    """`_append_attend` on a pool that lives on several devices. XLA cannot
+    partition a Mosaic kernel, so both kernels run per lane under
+    ``shard_map``, with the pool laid out as `paged_pool_spec` places it:
+
+      * replicated: every lane runs them on its own copy of the whole pool
+        (the same values on every lane) — the pool never leaves its lane;
+      * pages split over the ``model`` lanes: every lane gathers the pages
+        of ``layer``, runs the kernels on that one layer, and keeps its own
+        pages of the result — one layer crosses the lanes per layer, never
+        the whole pool.
+
+    Every other operand is replicated; the output is the same on every
+    lane."""
+    spec = paged_pool_spec(k_pool.shape, mesh)
+    lanes = spec[1]
+    run = functools.partial(_append_attend, interpret=interpret)
+
+    def lane(q, k_new, v_new, kp, vp, layer, *rest):
+        if lanes is None:
+            return run(q, k_new, v_new, kp, vp, layer, *rest)
+        n = kp.shape[1]
+        k_l, v_l = (jax.lax.all_gather(pool[layer], lanes, axis=0,
+                                       tiled=True)[None] for pool in (kp, vp))
+        out, k_l, v_l = run(q, k_new, v_new, k_l, v_l, jnp.int32(0), *rest)
+        first = jax.lax.axis_index(lanes) * n
+        kp, vp = (jax.lax.dynamic_update_index_in_dim(
+            pool, jax.lax.dynamic_slice_in_dim(new[0], first, n, axis=0),
+            layer, axis=0) for pool, new in ((kp, k_l), (vp, v_l)))
+        return out, kp, vp
+
+    rep = P()
+    return jax.shard_map(
+        lane, mesh=mesh,
+        in_specs=(rep, rep, rep, spec, spec, rep, rep, rep, rep, rep),
+        out_specs=(rep, spec, spec), check_vma=False,
+    )(q, k_new, v_new, k_pool, v_pool, layer, tables, write_page, write_off,
+      lengths)
 
 
 # ---------------------------------------------------------------------------

@@ -111,6 +111,15 @@ def fit_spec(parts, shape, mesh: Mesh) -> P:
     return P(*fitted)
 
 
+def paged_pool_spec(shape, mesh) -> P:
+    """PartitionSpec for a paged pool's ``(L, pages, Hkv, page, D)`` arrays:
+    pages over the ``model`` lanes (the context dim of the paper's per-lane
+    SRAM tiling). `fit_spec` drops the axis when the page count doesn't
+    divide, and the pool is then replicated. ``mesh`` may be abstract."""
+    tp = "model" if "model" in mesh.axis_names else None
+    return fit_spec((None, tp, None, None, None), shape, mesh)
+
+
 def param_spec_tree(params_or_specs, mesh: Mesh, *, strategy: str = "paper_tree",
                     mode: str = "serve", fsdp: bool = False,
                     moe_sharding: str = "tp"):

@@ -547,10 +547,13 @@ class Model:
         return logits, dataclasses.replace(state, k_pool=k_pool, v_pool=v_pool)
 
     def _paged_decode_kernel(self, p, state, token_or_embed, pos, adapter_idx):
-        """Pallas path: per layer, scatter the token into its page and run
-        `paged_flash_decode` — the block table rides in via scalar prefetch
-        and picks which pool page each context step DMAs HBM→VMEM. No
-        contiguous view is ever materialized."""
+        """Pallas path: per layer, write the token into its page
+        (`paged_kv_append`) and run `paged_flash_decode` — the block table
+        rides in via scalar prefetch and picks which pool page each context
+        step DMAs HBM→VMEM. The layer scan carries the whole pool; each
+        layer writes into it in place and the kernel reads its layer of
+        that same buffer, so neither a contiguous view nor a per-layer copy
+        of the pool is materialized."""
         cfg, mode = self.cfg, self.mode
         interpret = jax.default_backend() == "cpu"
         kw = {"fuse": self.fuse_proj, "kv_dtype": self.kv_widen}
@@ -561,37 +564,34 @@ class Model:
         else:
             x = token_or_embed.astype(self.dtype)
 
-        def block(lp, h, kp_l, vp_l):
+        def block(lp, h, kp, vp, layer):
             hn = layers.rms_norm(h, lp["norm1"]["w"], cfg.norm_eps)
-            a, kp_l, vp_l = attn_mod.gqa_decode_paged(
-                lp["attn"], hn, kp_l, vp_l, state.tables, state.write_page,
+            a, kp, vp = attn_mod.gqa_decode_paged(
+                lp["attn"], hn, kp, vp, layer, state.tables, state.write_page,
                 state.write_off, state.lengths, pos, cfg, mode,
-                use_kernel=True, interpret=interpret, **kw)
+                interpret=interpret, **kw)
             h = h + a
             h2 = layers.rms_norm(h, lp["norm2"]["w"], cfg.norm_eps)
             if "moe" in lp:
                 f, _ = moe_mod.moe_ffn(lp["moe"], h2, cfg, mode, **kw)
             else:
                 f = layers.apply_ffn(lp["ffn"], h2, cfg.ffn_kind, mode, **kw)
-            return h + f, kp_l, vp_l
+            return h + f, kp, vp
 
         prefix = p.get("prefix", [])
         kd = len(prefix)
         kp, vp = state.k_pool, state.v_pool
         for i, lp in enumerate(prefix):
-            x, k_l, v_l = block(lp, x, kp[i], vp[i])
-            kp = kp.at[i].set(k_l)
-            vp = vp.at[i].set(v_l)
+            x, kp, vp = block(lp, x, kp, vp, jnp.int32(i))
 
-        def body(h, inp):
-            lp, k_l, v_l = inp
-            h, k2, v2 = block(lp, h, k_l, v_l)
-            return h, (k2, v2)
+        def body(carry, inp):
+            lp, layer = inp
+            return block(lp, *carry, layer), None
 
-        x, (n_k, n_v) = jax.lax.scan(body, x, (p["layers"], kp[kd:], vp[kd:]))
-        with names.scope(names.KV_APPEND):
-            kp = jax.lax.dynamic_update_slice_in_dim(kp, n_k, kd, 0)
-            vp = jax.lax.dynamic_update_slice_in_dim(vp, n_v, kd, 0)
+        n_layers = kp.shape[0]
+        (x, kp, vp), _ = jax.lax.scan(
+            body, (x, kp, vp),
+            (p["layers"], jnp.arange(kd, n_layers, dtype=jnp.int32)))
         x = layers.rms_norm(x, p["final_norm"]["w"], cfg.norm_eps)
         return self._logits(p, x), dataclasses.replace(state, k_pool=kp,
                                                        v_pool=vp)

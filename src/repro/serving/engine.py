@@ -134,6 +134,16 @@ def _prefill_jits(model):
     return fns
 
 
+def _specs(args):
+    """``args`` with each array replaced by its shape, dtype and placement
+    (committed arrays only: an uncommitted one goes wherever the call puts
+    it): enough to lower the call again after it has consumed its arrays."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None)
+        if isinstance(a, jax.Array) else a, args)
+
+
 class _AotCall:
     """An ahead-of-time compiled executable behind the dispatch interface.
 
@@ -383,8 +393,7 @@ class ServeEngine:
                  spec_adaptive: bool = False,
                  scheduler=None, adapters=None, tiered=None,
                  prefetch: bool = False,
-                 tracer: Optional[Tracer] = None, profiler=None,
-                 donate_decode_state: bool = False):
+                 tracer: Optional[Tracer] = None, profiler=None):
         assert model.mode in ("serve", "qlora")
         assert prefill_chunk is None or prefill_chunk >= 1, \
             "prefill_chunk must be >= 1 tokens (or None for monolithic prefill)"
@@ -533,14 +542,15 @@ class ServeEngine:
         # Every jitted entry point rides a CompileWatch: cache growth bumps
         # stats.jit_compiles and emits a jit_compile instant naming the
         # offending shape bucket (recompile stalls become visible in-trace).
-        # donate_decode_state buys the decode step its input KV buffers
-        # (state is replaced wholesale by commit(), so the engine never
-        # reads a donated buffer again) — halves decode's transient KV
-        # footprint, the enabler for serving max_len-sized pools per replica.
-        decode_jit = (jax.jit(self._decode_fn, donate_argnames=("kv_state",))
-                      if donate_decode_state else jax.jit(self._decode_fn))
-        self.donate_decode_state = donate_decode_state
-        self._decode = _watch(decode_jit, "decode_step")
+        # The decode step consumes the KV state it is handed: commit()
+        # replaces the state wholesale right after the dispatch, so the
+        # engine never reads a donated buffer again, and the step's new
+        # pool (or dense cache) is written into the buffer it came in —
+        # the paged kernel path then updates the pool in place and never
+        # holds a second copy of it.
+        self._decode_jit = jax.jit(self._decode_fn,
+                                   donate_argnames=("kv_state",))
+        self._decode = _watch(self._decode_jit, "decode_step")
         self._sample = _watch(jax.jit(self._sample_fn,
                                       static_argnames=("use_topp",
                                                        "use_seeds")),
@@ -610,6 +620,10 @@ class ServeEngine:
                 self.stats.tick_gap_ms_sum += gap
                 self.stats.tick_gaps += 1
                 self.trace.counter("tick_gap_ms", gap, pid=self._tpid)
+        if self.profiler is not None:
+            # the probe lowers from shapes taken now: the call may consume
+            # (donate) the arrays it is handed
+            probe = _specs(args)
         out = fn(*args, **kwargs)
         if self.profiler is not None:
             # profiling blocks the dispatch so the measured wall is real
@@ -617,7 +631,7 @@ class ServeEngine:
             out = jax.block_until_ready(out)
             self.profiler.observe_call(
                 getattr(fn, "name", getattr(fn, "__name__", "fn")),
-                fn, args, kwargs, time.perf_counter() - t,
+                fn, probe, kwargs, time.perf_counter() - t,
                 compiled=getattr(fn, "last_compiled", False))
         self._t_dev_end = time.perf_counter()
         self._dispatch_tid = tid
@@ -812,7 +826,11 @@ class ServeEngine:
         ``stats.warmup_compiles`` records the executables built here and
         ``stats.jit_compiles`` resets to **0**, so any nonzero value after
         serving is a real recompile stall (the zero-recompile contract the
-        sharded test lane asserts).
+        sharded test lane asserts). The returned ``decode_alias_bytes`` and
+        ``decode_temp_bytes`` are the compiled decode step's memory analysis
+        at the largest table view: the argument bytes its outputs alias (the
+        donated KV state, when the step updates it in place) and the bytes
+        it holds besides its arguments and outputs.
 
         Must run on an idle engine (no pending pipelined ticks)."""
         assert not self._pending, "warmup_aot needs an idle engine"
@@ -907,8 +925,17 @@ class ServeEngine:
         z_b = jnp.asarray(np.zeros((B,), bool))
         last = None
         logits = None
+        largest = None
         for state in self.kv.warmup_decode_states():
-            logits, _ = self._decode(params, state, fed, posv, aidx_dec)
+            args = (params, state, fed, posv, aidx_dec)
+            # shapes taken before the call consumes the state; the states
+            # come smallest view first
+            largest = _specs(args)
+            logits, _ = self._decode(*args)
+        decode_mem = None
+        if largest is not None:
+            decode_mem = self._decode_jit.lower(*largest).compile() \
+                .memory_analysis()
         if logits is not None:
             for use_topp in (False, True):
                 for use_seeds in (False, True):
@@ -950,6 +977,10 @@ class ServeEngine:
             "jit_warmed": jit_warmed,
             "compiles": jit_warmed + n_aot,
             "wall_s": round(time.perf_counter() - t0, 3),
+            "decode_alias_bytes": getattr(decode_mem, "alias_size_in_bytes",
+                                          None),
+            "decode_temp_bytes": getattr(decode_mem, "temp_size_in_bytes",
+                                         None),
         }
 
     # -- engine internals ------------------------------------------------------------

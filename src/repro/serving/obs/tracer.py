@@ -309,10 +309,11 @@ class CompileWatch:
             self._seen_sigs.add(sig)
             if compiled:
                 self.compiles += 1
+        # read before the call, which may consume (donate) its arrays
+        shapes = self._shapes(args) if compiled else None
         out = self._fn(*args, **kwargs)
         self.last_compiled = compiled
         if compiled:
-            shapes = self._shapes(args)
             if self.on_compile is not None:
                 self.on_compile(self.name, shapes)
             self.tracer.instant("jit_compile", pid=self.pid, fn=self.name,

@@ -34,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh
 from repro.models.sharding import (fit_spec, kv_cache_spec_tree,
-                                   param_spec_tree, to_named)
+                                   paged_pool_spec, param_spec_tree, to_named)
 
 Params = Any
 
@@ -64,12 +64,10 @@ def shard_params(params: Params, mesh: Mesh, *,
 
 
 def pool_spec(pool, mesh: Mesh) -> P:
-    """PartitionSpec for the paged pool's ``(L, pages, Hkv, page, D)``
-    arrays: pages over the ``model`` lanes (the context dim of the paper's
-    per-lane SRAM tiling). `fit_spec` drops the axis when the page count
-    doesn't divide — tiny test pools simply replicate."""
-    tp = "model" if "model" in mesh.axis_names else None
-    return fit_spec((None, tp, None, None, None), pool.k.shape, mesh)
+    """PartitionSpec for the paged pool (`paged_pool_spec`: pages over the
+    ``model`` lanes; tiny test pools simply replicate). The decode step's
+    Pallas kernels read the pool per lane under this same spec."""
+    return paged_pool_spec(pool.k.shape, mesh)
 
 
 def shard_engine(engine, mesh: Mesh):
