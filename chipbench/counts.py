@@ -8,7 +8,7 @@ are 2 bits; the KV cache is fp8 (1 byte per element).
 """
 from __future__ import annotations
 
-from chipbench.modelcfg import Dims
+from chipbench.reference.model import Dims
 
 KV_BYTES = 1          # fp8 e4m3 cache element
 ACT_BYTES = 2         # bf16 activations

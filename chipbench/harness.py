@@ -1,32 +1,34 @@
 """Drive the program under test through one run of one cell.
 
 The program is reached only through its public entry points:
-``repro.launch.serve.build_engine`` (paged fp8 KV, batched prefill) and
+``repro.launch.serve.build_engine`` (paged fp8 KV, batched prefill, and
+the configuration's ``engine`` block) and
 ``repro.serving.runtime.AsyncServeRuntime`` (depth 1) over ``Gateway``.
 A client receives each token in the request's ``stream_cb``, which the
 runtime's backlog thread calls; the harness stamps it there. Everything
 that turns those stamps, counters and the trace into numbers lives in the
-yardstick modules beside this one, none of which imports the program.
+yardstick modules beside this one, none of which imports the program: the
+trace reduction, the metric readers (``metrics/<name>.py``) and the
+reference module that the configuration names.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
-import importlib.util
 import queue
 import shutil
 import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from pathlib import Path
 from typing import Dict, List, Optional
 
 import numpy as np
 
-from chipbench import peaks, trace_reduce
+from chipbench import peaks, trace_names, trace_reduce
 from chipbench import traffic as traffic_mod
-from chipbench.modelcfg import ROOT, load_config
-from chipbench.reference.model import Seq, served_gaps
+from chipbench.modelcfg import ROOT, load_config, load_module, reference
+from chipbench.reference import Seq
 
 #: Threads that hand requests to the runtime: ``submit`` blocks until the
 #: dispatch thread binds the request, so a few in parallel keep the
@@ -44,6 +46,11 @@ WARM_S = 1200.0
 #: others drawn from the seed.
 SAMPLE = 8
 TRACE_DIR = ROOT.parent / ".chipbench" / "trace"
+#: Where the reader of per-layer metric ``<name>`` is: ``<name>.py``.
+METRICS = ROOT / "metrics"
+#: ``engine`` keys that ``build_engine`` takes under another name; every
+#: other key of the block goes to it under its own.
+ENGINE_NAMES = {"tenants": "n_adapters"}
 
 
 def log(msg: str) -> None:
@@ -75,12 +82,17 @@ class Client:
             self.on_done(self)
 
 
-def _load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+def snapshot(stats) -> Dict:
+    """Every numeric field of the engine's stats record as it reads now,
+    and a copy of each field that maps names to numbers (``phase_ms``)."""
+    out = {}
+    for f in dataclasses.fields(stats):
+        v = getattr(stats, f.name)
+        if isinstance(v, dict):
+            out[f.name] = dict(v)
+        elif isinstance(v, (int, float)):
+            out[f.name] = v
+    return out
 
 
 def percentile(values, q: float) -> Optional[float]:
@@ -106,6 +118,7 @@ class Run:
         self.preset = preset
         self.control = control
         self.dims = self.cfg["dims"]
+        self.ref = reference(self.cfg["reference"])
         self.model_seed = seed % (2 ** 31 - 1)
         self.tracer: Optional[threading.Thread] = None
         self.trace_span = None
@@ -122,14 +135,10 @@ class Run:
         # compiles small ones per prompt length (its KV writes), and set-up
         # must find them all in the cache after a cell's first run
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        e = self.cfg["engine"]
-        eng = build_engine(
-            self.cfg["arch"], self.preset, slots=e["slots"],
-            max_len=e["max_len"], prefill="batched", kv="paged",
-            page=e["page"], seed=self.model_seed,
-            n_adapters=e.get("tenants", 0),
-            adapter_rank=e.get("adapter_rank", 8),
-            adapter_budget_kb=e.get("adapter_budget_kb"))
+        # a key build_engine does not take fails here, at set-up
+        kw = {ENGINE_NAMES.get(k, k): v for k, v in self.cfg["engine"].items()}
+        eng = build_engine(self.cfg["arch"], self.preset, prefill="batched",
+                           kv="paged", seed=self.model_seed, **kw)
         warm = eng.warmup_aot(max_prompt_len=self.traffic["prompt_len"]["max"])
         log(f"engine built and warmed: {warm['compiles']} executables "
             f"({warm['wall_s']:.1f} s of warm-up)")
@@ -246,8 +255,7 @@ class Run:
 
     # -- tracing inside the window --------------------------------------------
     def window_start(self, w0: float) -> None:
-        st = self.eng.stats
-        self.counters0 = (st.ticks, st.tokens_out)
+        self.stats = {"start": snapshot(self.eng.stats)}
         mem = self.devices[0].memory_stats() or {}
         log(f"set-up done at {w0 - self.t_start:.2f} s; window opens with "
             f"{mem.get('bytes_in_use')} bytes in use on the device (peak so "
@@ -294,12 +302,13 @@ class Run:
                 clients, w0, w1 = self.open_loop(stream, pool)
             if self.tracer is not None:
                 self.tracer.join()
-            st = self.eng.stats
-            counters = {"ticks": st.ticks - self.counters0[0],
-                        "tokens_out": st.tokens_out - self.counters0[1],
+            s0 = self.stats["start"]
+            s1 = self.stats["end"] = snapshot(self.eng.stats)
+            counters = {"ticks": s1["ticks"] - s0["ticks"],
+                        "tokens_out": s1["tokens_out"] - s0["tokens_out"],
                         "slots": self.eng.max_slots,
-                        "jit_compiles": st.jit_compiles,
-                        "aot_fallbacks": st.aot_fallbacks}
+                        "jit_compiles": s1["jit_compiles"],
+                        "aot_fallbacks": s1["aot_fallbacks"]}
         finally:
             pool.shutdown(wait=True)
             self.rt.close(raise_on_poison=False)
@@ -406,10 +415,10 @@ class Run:
         seqs = self.sample(done)
         t0 = time.perf_counter()
         e = self.cfg["engine"]
-        out = served_gaps(self.dims, self.model_seed, seqs, e["max_len"],
-                          tenants=e.get("tenants", 0),
-                          rank=e.get("adapter_rank", 8), control=self.control,
-                          n_out=self.traffic["output_len"]["max"])
+        out = self.ref.served_gaps(
+            self.dims, self.model_seed, seqs, e["max_len"],
+            tenants=e.get("tenants", 0), rank=e.get("adapter_rank", 8),
+            control=self.control, n_out=self.traffic["output_len"]["max"])
         self.gaps = {"program": np.concatenate(out["gap"])}
         if self.control:
             self.gaps["control"] = np.concatenate(out["control_gap"])
@@ -447,7 +456,9 @@ class Run:
         if path is None or self.trace_span is None:
             log("no trace was recorded")
             return None
-        red = trace_reduce.reduce_file(path)
+        t0 = time.perf_counter()
+        red = trace_names.reduce_file(path)
+        log(f"trace reduced in {time.perf_counter() - t0:.1f} s")
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         if not red["marked"]:
             log("the trace holds no mark of its window")
@@ -461,14 +472,15 @@ class Run:
     def per_layer(self, records, counters, red) -> Dict:
         ctx = {"dims": self.dims, "trace": red, "counters": counters,
                "records": records, "cell": self.cell["name"],
-               "window": self.trace_span, "engine": self.cfg["engine"]}
+               "window": self.trace_span, "engine": self.cfg["engine"],
+               "config": self.cfg, "stats": self.stats}
         if red is not None and red["devices"]:
             ctx["peaks"] = peaks.peaks_for(self.devices[0].device_kind)
         out = {}
         for m in self.bench["per_layer"]:
             if self.cell["name"] not in m.get("workloads", [self.cell["name"]]):
                 continue
-            mod = _load_module(ROOT / "metrics" / f"{m['name']}.py")
+            mod = load_module(METRICS / f"{m['name']}.py")
             v = mod.read(ctx)
             if v is not None:
                 out[m["name"]] = {"value": v, "unit": m["unit"]}

@@ -1,58 +1,50 @@
-"""A configuration file of the benchmark, read into the shapes the yardstick
-needs (operation counts, the plain reference). Keys follow the model's
-published ``config.json``; ``configs/<name>.json`` holds them as run."""
+"""A configuration file of the benchmark, and the modules it is read with.
+
+``configs/<name>.json`` holds a configuration as it is run: its ``model``
+block keyed as the model's published ``config.json``, its ``engine`` block,
+and under ``reference`` the path (relative to ``chipbench/``) of the plain
+reference module that computes its block. That module also reads the
+``model`` block into the shape record the yardstick uses
+(``chipbench/reference/__init__.py`` states the contract).
+"""
 from __future__ import annotations
 
-import dataclasses
+import functools
+import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
+from types import ModuleType
 
 ROOT = Path(__file__).resolve().parent
 
 
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    act: str            # "relu2" | "gelu_tanh"
-    tied: bool          # head shares the embedding table
-    rope_theta: float
-    norm_eps: float
-
-    @property
-    def q_dim(self) -> int:
-        return self.heads * self.head_dim
-
-    @property
-    def kv_dim(self) -> int:
-        return self.kv_heads * self.head_dim
+def load_module(path) -> ModuleType:
+    """The Python file at ``path``, run as a module of its own: metric
+    readers and references are found by path, never imported by name."""
+    path = Path(path).resolve()
+    name = "chipbench_file" + re.sub(r"\W", "_", str(path.with_suffix("")))
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
-_ACTS = {"relu2": "relu2", "gelu_pytorch_tanh": "gelu_tanh"}
-
-
-def dims_of(model: dict) -> Dims:
-    """``Dims`` from the ``model`` block of a configuration file."""
-    return Dims(layers=int(model["num_hidden_layers"]),
-                d_model=int(model["hidden_size"]),
-                heads=int(model["num_attention_heads"]),
-                kv_heads=int(model["num_key_value_heads"]),
-                head_dim=int(model["head_dim"]),
-                d_ff=int(model["intermediate_size"]),
-                vocab=int(model["vocab_size"]),
-                act=_ACTS[model["hidden_act"]],
-                tied=bool(model["tie_word_embeddings"]),
-                rope_theta=float(model["rope_theta"]),
-                norm_eps=float(model["rms_norm_eps"]))
+@functools.lru_cache(maxsize=None)
+def reference(path: str) -> ModuleType:
+    """The reference module a configuration names (its ``reference`` key),
+    loaded once per process."""
+    return load_module(ROOT / path)
 
 
 def load_config(path) -> dict:
-    """A configuration file, with its ``Dims`` under ``"dims"``."""
+    """A configuration file, with its reference's shape record of the
+    ``model`` block under ``"dims"``."""
     cfg = json.loads(Path(path).read_text())
-    cfg["dims"] = dims_of(cfg["model"])
+    if "reference" not in cfg:
+        raise KeyError(f"{path}: a configuration names its reference module "
+                       "under 'reference'")
+    cfg["dims"] = reference(cfg["reference"]).dims_of(cfg["model"])
     return cfg

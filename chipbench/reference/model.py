@@ -2,7 +2,8 @@
 ``correct``. Written from the configuration's equations in ``jax.numpy``,
 with no kernel, cache, packing or batching of the program under test.
 
-The block, as the served configurations state it (both are pre-norm):
+The block of ``configs/bitnet-2b.json`` (pre-norm); the tiny StarCoder2
+test configuration takes the same module with tanh-GELU and an untied head:
 
     h  = rmsnorm(x)                       unit gain, eps from the config
     q, k, v = h·Wq, h·Wk, h·Wv  (+ the tenant's low-rank path on q and v)
@@ -29,19 +30,61 @@ configurations state.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import List, NamedTuple, Optional
+from typing import List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.modelcfg import Dims
+from chipbench.reference import Seq
 
 HI = jax.lax.Precision.HIGHEST
 QUANT_EPS = 1e-8
 FP8_MAX = 448.0
 NEG = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class Dims:
+    layers: int
+    d_model: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    d_ff: int
+    vocab: int
+    act: str            # "relu2" | "gelu_tanh"
+    tied: bool          # head shares the embedding table
+    rope_theta: float
+    norm_eps: float
+
+    @property
+    def q_dim(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def kv_dim(self) -> int:
+        return self.kv_heads * self.head_dim
+
+
+_ACTS = {"relu2": "relu2", "gelu_pytorch_tanh": "gelu_tanh"}
+
+
+def dims_of(model: dict) -> Dims:
+    """``Dims`` from the ``model`` block of a configuration file."""
+    return Dims(layers=int(model["num_hidden_layers"]),
+                d_model=int(model["hidden_size"]),
+                heads=int(model["num_attention_heads"]),
+                kv_heads=int(model["num_key_value_heads"]),
+                head_dim=int(model["head_dim"]),
+                d_ff=int(model["intermediate_size"]),
+                vocab=int(model["vocab_size"]),
+                act=_ACTS[model["hidden_act"]],
+                tied=bool(model["tie_word_embeddings"]),
+                rope_theta=float(model["rope_theta"]),
+                norm_eps=float(model["rms_norm_eps"]))
 
 
 def ternarise(w):
@@ -196,12 +239,6 @@ def _gaps(x, pos, served, head, ref_logits_of=None, *, dims: Dims,
         g, _ = jax.lax.map(lambda a: one((*a, None)), (x, pos, served))
         return g, None
     return jax.lax.map(one, (x, pos, served, ref_logits_of))
-
-
-class Seq(NamedTuple):
-    prompt: np.ndarray          # int32 prompt ids
-    served: np.ndarray          # int32 tokens the program served
-    tenant: Optional[int]       # adapter index, None for the base model
 
 
 def adapter_stacks(dims: Dims, seed: int, tenants: int, rank: int):

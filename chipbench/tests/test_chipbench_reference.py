@@ -4,8 +4,8 @@ tenants, agree with the reference's; the fp8 control does not."""
 import numpy as np
 import pytest
 
-from chipbench.modelcfg import ROOT, load_config
-from chipbench.reference.model import Seq, logits, served_gaps
+from chipbench.modelcfg import ROOT, load_config, reference
+from chipbench.reference import Seq
 
 TINY = ROOT / "tests" / "tiny"
 SEED = 2 ** 31 + 77
@@ -61,6 +61,7 @@ def _rel(a, b):
 def test_reference_agrees_with_prefill_and_cached_decode(arch):
     cfg = load_config(TINY / f"{arch}.json")
     dims, e = cfg["dims"], cfg["engine"]
+    ref_mod = reference(cfg["reference"])
     rng = np.random.default_rng(3)
     tenants = e.get("tenants", 0)
     prompts = [(rng.integers(0, dims.vocab, 16 + 9 * i).astype(np.int32),
@@ -69,14 +70,14 @@ def test_reference_agrees_with_prefill_and_cached_decode(arch):
     seqs, prog = _program_greedy(arch, dims, e, SEED % (2 ** 31 - 1),
                                  prompts, 10)
     kw = dict(tenants=tenants, rank=e.get("adapter_rank", 8))
-    ref = logits(dims, SEED % (2 ** 31 - 1), seqs, e["max_len"], **kw)
-    ctl = logits(dims, SEED % (2 ** 31 - 1), seqs, e["max_len"], low=True,
-                 **kw)
+    seed, length = SEED % (2 ** 31 - 1), e["max_len"]
+    ref = ref_mod.logits(dims, seed, seqs, length, **kw)
+    ctl = ref_mod.logits(dims, seed, seqs, length, low=True, **kw)
     err, ctl_err = _rel(prog, ref), _rel(ctl, ref)
     assert err < TOL < ctl_err, (err, ctl_err)
     # a reference of the wrong seed misses by far more
-    other = logits(dims, SEED % (2 ** 31 - 1) + 1, seqs, e["max_len"], **kw)
+    other = ref_mod.logits(dims, seed + 1, seqs, length, **kw)
     assert _rel(other, ref) > 0.5
     # greedy gaps: a token the program served is (near) the reference's best
-    gaps = served_gaps(dims, SEED % (2 ** 31 - 1), seqs, e["max_len"], **kw)
+    gaps = ref_mod.served_gaps(dims, seed, seqs, length, **kw)
     assert max(g.max() for g in gaps["gap"]) < 0.05

@@ -12,7 +12,9 @@ import numpy as np
 import pytest
 
 from chipbench import counts, peaks, trace_reduce, traffic
-from chipbench.modelcfg import ROOT, dims_of, load_config
+from chipbench.modelcfg import ROOT, load_config
+from chipbench.reference.model import dims_of
+from chipbench.tests.readers import context, reader
 
 REPO = ROOT.parent
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -214,45 +216,30 @@ def test_command_refuses_without_a_tpu():
     assert p.stdout.strip() == ""
 
 
-def _reader(name):
-    from chipbench.harness import _load_module
-    return _load_module(ROOT / "metrics" / f"{name}.py").read
-
-
-def _ctx(red):
-    dims = load_config(ROOT / "configs" / "bitnet-2b.json")["dims"]
-    tokens = [(0.5 + i * 1e-3, 300, i % 4 != 0) for i in range(640)]
-    return {"dims": dims, "trace": red, "peaks": peaks.peaks_for("TPU v5 lite"),
-            "counters": {"ticks": 10, "tokens_out": 600, "slots": 64},
-            "records": {"tokens": tokens},
-            "window": ((0.0, 100.0), (3.0, 103.0)),
-            "engine": {"adapter_rank": 8, "tenants": 16}}
-
-
 def test_metric_readers_on_a_synthetic_trace():
     red = {"devices": 1, "busy_s": 2.4, "window_s": 3.0,
            "modules": {"jit__decode_fn": 1.0, "jit__fresh_prefill": 0.1},
            "ops": {"paged_flash_decode": 0.2, "batched_lora_matmul": 0.01},
            "op_calls": {"paged_flash_decode": 300,
                         "batched_lora_matmul": 600}}
-    ctx = _ctx(red)
-    assert _reader("device_idle_share.decode")(ctx) == pytest.approx(20.0)
-    assert _reader("batch_occupancy")(ctx) == pytest.approx(93.75)
+    ctx = context(red)
+    assert reader("device_idle_share.decode")(ctx) == pytest.approx(20.0)
+    assert reader("batch_occupancy")(ctx) == pytest.approx(93.75)
     dims = ctx["dims"]
     flops = 640 * counts.decode_token_flops(dims, 300) \
         + 480 * counts.adapter_flops_per_token(dims, 8)
-    assert _reader("decode_mfu")(ctx) == pytest.approx(
+    assert reader("decode_mfu")(ctx) == pytest.approx(
         100 * flops / 197e12)
     f, b = counts.flash_decode_call(dims, [300] * 640)
-    assert _reader("paged_flash_decode_roofline")(ctx) == pytest.approx(
+    assert reader("paged_flash_decode_roofline")(ctx) == pytest.approx(
         100 * max(30 * f / 197e12, 30 * b / 819e9) / 0.2)
-    assert 0 < _reader("batched_lora_matmul_roofline")(ctx) < 100
+    assert 0 < reader("batched_lora_matmul_roofline")(ctx) < 100
 
 
 @pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]
                                   if m["source"] == "device_trace"])
 def test_trace_readers_read_nothing_without_a_trace(name):
-    assert _reader(name)(_ctx(None)) is None
+    assert reader(name)(context(None)) is None
     empty = {"devices": 0, "busy_s": 0.0, "window_s": 3.0, "modules": {},
              "ops": {}, "op_calls": {}}
-    assert _reader(name)(_ctx(empty)) is None
+    assert reader(name)(context(empty)) is None

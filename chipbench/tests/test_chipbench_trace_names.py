@@ -5,17 +5,13 @@ import time
 
 import pytest
 
-from chipbench import peaks, trace_names, trace_reduce
-from chipbench.modelcfg import ROOT, load_config
+from chipbench import trace_names, trace_reduce
+from chipbench.modelcfg import ROOT
+from chipbench.tests.readers import context, reader
 
 CHIP_TRACE = str(ROOT / "testdata" / "small_v5e.xplane.pb")
 NEW_READERS = ("ternary_proj_roofline", "lm_head_roofline",
                "admission_device_share")
-
-
-def _reader(name):
-    from chipbench.harness import _load_module
-    return _load_module(ROOT / "metrics" / f"{name}.py").read
 
 
 def test_xplane_schema_reads_metadata_a_profile_data_hides():
@@ -141,16 +137,6 @@ def test_idle_gaps_prefer_serve_spans():
     assert plain == "$threading.py acquire"
 
 
-def _ctx(red):
-    dims = load_config(ROOT / "configs" / "bitnet-2b.json")["dims"]
-    tokens = [(0.5 + i * 1e-3, 300, i % 4 != 0) for i in range(640)]
-    return {"dims": dims, "trace": red, "peaks": peaks.peaks_for("TPU v5 lite"),
-            "counters": {"ticks": 10, "tokens_out": 600, "slots": 64},
-            "records": {"tokens": tokens},
-            "window": ((0.0, 100.0), (3.0, 103.0)),
-            "engine": {"adapter_rank": 8, "tenants": 16}}
-
-
 def test_new_readers_on_a_synthetic_trace():
     red = {"devices": 1, "busy_s": 2.5, "window_s": 3.0,
            "modules": {"jit__decode_fn": 2.0}, "module_runs": {
@@ -160,33 +146,33 @@ def test_new_readers_on_a_synthetic_trace():
                                          "attn": 1.0},
                       "jit__fresh_prefill": {"ternary_proj": 9.0}},
            "by_span": {"serve.admit": 0.25, "serve.decode": 2.0}}
-    ctx = _ctx(red)
+    ctx = context(red)
     d = ctx["dims"]
     w = 1_553_203_200
     io = 30 * 2 * sum(k + n for k, n in [(2560, 2560), (2560, 640),
                                          (2560, 640), (2560, 2560),
                                          (2560, 6912), (6912, 2560)])
     need = max(2 * w * 640 / 197e12, (w // 4 * 10 + io * 640) / 819e9)
-    assert _reader("ternary_proj_roofline")(ctx) == pytest.approx(
+    assert reader("ternary_proj_roofline")(ctx) == pytest.approx(
         100 * need / 0.2)
     h = 2560 * 128256
     need = max(2 * h * 640 / 197e12,
                (h // 4 * 10 + d.vocab * 4 * 640) / 819e9)
-    assert _reader("lm_head_roofline")(ctx) == pytest.approx(
+    assert reader("lm_head_roofline")(ctx) == pytest.approx(
         100 * need / 0.05)
-    assert _reader("admission_device_share")(ctx) == pytest.approx(10.0)
+    assert reader("admission_device_share")(ctx) == pytest.approx(10.0)
 
 
 @pytest.mark.parametrize("name", NEW_READERS)
 def test_new_readers_read_nothing_without_the_programs_names(name):
-    assert _reader(name)(_ctx(None)) is None
+    assert reader(name)(context(None)) is None
     # what a program without scopes or spans leaves: the reduction alone
     bare = {"devices": 1, "busy_s": 2.5, "window_s": 3.0,
             "modules": {"jit__decode_fn": 2.0},
             "module_runs": {"jit__decode_fn": 10}, "ops": {}, "op_calls": {}}
-    assert _reader(name)(_ctx(bare)) is None
+    assert reader(name)(context(bare)) is None
     empty = dict(bare, scopes={}, spans={}, by_span={})
-    assert _reader(name)(_ctx(empty)) is None
+    assert reader(name)(context(empty)) is None
 
 
 def test_engine_spans_in_a_cpu_trace(tmp_path):
@@ -222,3 +208,30 @@ def test_engine_spans_in_a_cpu_trace(tmp_path):
         assert spans[name][1] >= 1 and spans[name][0] >= 0
     assert spans["serve.kv_write"][1] == spans["serve.prefill"][1] == 3
     json.dumps(red)
+
+
+#: The per-layer readers the benchmark had before the harness reduced its
+#: traces by the program's names as well.
+PLAIN_READERS = ("device_idle_share.decode", "decode_mfu",
+                 "paged_flash_decode_roofline",
+                 "batched_lora_matmul_roofline", "batch_occupancy")
+
+
+def _as_decode_step(red):
+    """The chip trace's kernel runs under ``jit(<lambda>)``; name its
+    executable the decode step's, as the readers look for it."""
+    for table in ("modules", "module_runs"):
+        red[table] = {"jit__decode_fn": v for v in red[table].values()}
+    return red
+
+
+@pytest.mark.parametrize("name", PLAIN_READERS)
+def test_plain_readers_read_alike_from_either_reduction(name):
+    """The harness reduces a trace by ``trace_names`` now; a reader that
+    took ``trace_reduce``'s keys reads the same value from it."""
+    red = _as_decode_step(trace_reduce.reduce_file(CHIP_TRACE))
+    named = _as_decode_step(trace_names.reduce_file(CHIP_TRACE))
+    before, after = reader(name)(context(red)), reader(name)(context(named))
+    assert after == before
+    # the trace holds what each reader looks for, but for the LoRA kernel
+    assert (before is None) == (name == "batched_lora_matmul_roofline")
